@@ -12,9 +12,10 @@ from repro.core.clustering import build_tree
 from repro.core.fl_step import (abstract_state, build_fl_round_step,
                                 init_state, n_clients_for)
 from repro.core.topology import compile_tree, flat_schedule, validate_schedule
+from repro.launch.mesh import make_host_mesh
 from repro.models import inputs as minputs
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(data=4, model=2)
 cfg = smoke_config(get_arch("qwen2-7b"))
 shape = ShapeConfig("t", 32, 8, "train")
 

@@ -11,9 +11,10 @@ import numpy as np
 
 from repro.configs.base import get_arch, smoke_config
 from repro.dist import sharding as shd
+from repro.launch.mesh import make_host_mesh
 from repro.models.moe import moe_apply, moe_decl
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(data=2, model=4)
 base = smoke_config(get_arch("kimi-k2-1t-a32b"))
 # E=4 divisible by model=4; generous capacity so neither path drops
 cfg = base.replace(moe=dataclasses.replace(base.moe, n_experts=4, top_k=2,

@@ -37,17 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:      # jax < 0.6 experimental API (pinned range in pyproject)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-except ImportError:  # pragma: no cover — modern jax: top-level shard_map
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
 from repro.api.strategies import AggregationStrategy, get_strategy
 from repro.core.topology import AggSchedule
 from repro.dist.compression import dequantize_int8, quantize_int8
@@ -182,9 +171,9 @@ def aggregate_params(params, weights, mesh: Mesh, axis: str,
             lambda m, p: m.astype(p.dtype), mean, p_local)
         return tuple(jax.tree_util.tree_leaves(out))
 
-    out_leaves = shard_map(
+    out_leaves = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis),) + spec_leaves + (spec_leaves if ref_leaves else ()),
-        out_specs=spec_leaves,
+        out_specs=spec_leaves, check_vma=False,
     )(weights, *(p_leaves + list(ref_leaves)))
     return jax.tree_util.tree_unflatten(treedef, out_leaves)

@@ -258,8 +258,14 @@ def init_state(cfg: ArchConfig, mesh: Mesh, key, total_steps: int = 10000,
         return params
     params = jax.jit(mk, out_shardings=shardings)()
     init = jax.vmap(opt.init) if n > 1 else opt.init
-    opt_state = jax.jit(init)(params)
-    return {"params": params, "opt": opt_state, "step": jnp.zeros((), jnp.int32)}
+    # the whole state is laid out as the round step returns it, so the
+    # step's second call reuses the first call's compilation
+    opt_shardings = jax.tree_util.tree_map(
+        lambda sp: NamedSharding(mesh, sp),
+        opt_state_specs(cfg, mesh, opt.name))
+    opt_state = jax.jit(init, out_shardings=opt_shardings)(params)
+    step = jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(mesh, P()))
+    return {"params": params, "opt": opt_state, "step": step}
 
 
 def abstract_state(cfg: ArchConfig, mesh: Mesh, opt_name: str):

@@ -58,14 +58,7 @@ def qagg(q: jax.Array, scales: jax.Array, weights: jax.Array,
     if use == "ref":
         return qagg_ref(q3, s3, weights).reshape(shape)
     interpret = jax.default_backend() != "tpu"
-    R = q3.shape[1]
-    rows_block = max(1, min(R, DEFAULT_BLOCK // max(G, 1)))
-    pad = (-R) % rows_block
-    if pad:
-        q3 = jnp.pad(q3, ((0, 0), (0, pad), (0, 0)))
-        s3 = jnp.pad(s3, ((0, 0), (0, pad), (0, 0)))
-    out = qagg_pallas(q3, s3, weights, rows_block, interpret=interpret)
-    return out[:R].reshape(shape)
+    return qagg_pallas(q3, s3, weights, interpret=interpret).reshape(shape)
 
 
 def fedavg_pytree(params_stacked, weights, force: str = "auto"):

@@ -1,30 +1,37 @@
+"""Multi-pod dry-run: lower and compile every (arch x shape) cell of the
+production v5e meshes on placeholder host devices, and price each compiled
+program against the v5e roofline.
+
+    python -m repro.launch.dryrun --arch hymba-1.5b --shape decode_32k
+
+The CLI sizes the host platform to 512 placeholder devices; a caller that
+imports ``lower_cell`` sets ``XLA_FLAGS`` itself before jax initialises.
+"""
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialization.  Only the dry-run uses 512 placeholder
-# devices; tests/benches see the real host device.
+import time
+import traceback
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.configs.base import (SHAPES, get_arch, list_archs,  # noqa: E402
+from repro.configs.base import (SHAPES, get_arch, list_archs,
                                 shape_applicable)
-from repro.core.clustering import build_tree  # noqa: E402
-from repro.core.fl_step import (abstract_state, build_fl_round_step,  # noqa: E402
+from repro.core.clustering import build_tree
+from repro.core.fl_step import (abstract_state, build_fl_round_step,
                                 client_axis_for, n_clients_for)
-from repro.core.topology import compile_tree, flat_schedule  # noqa: E402
-from repro.dist import sharding as shd  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.roofline import build_roofline, model_flops  # noqa: E402
-from repro.models import inputs as minputs  # noqa: E402
-from repro.models import model_api  # noqa: E402
-from repro.optim.api import make_optimizer  # noqa: E402
+from repro.core.topology import compile_tree, flat_schedule
+from repro.dist import sharding as shd
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import build_roofline, model_flops
+from repro.models import inputs as minputs
+from repro.models import model_api
+from repro.optim.api import make_optimizer
+
+# The production meshes model a pod of TPU v5e chips.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 # --------------------------------------------------------------------------
@@ -177,7 +184,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
         mf = model_flops(active_p, tokens, "serve")
 
     n_dev = mesh.devices.size
-    rf = build_roofline(compiled, n_dev, mf)
+    rf = build_roofline(compiled, n_dev, mf, TARGET_DEVICE_KIND)
     rec = {
         "arch": arch_name, "shape": shape_name,
         "mesh": "multipod" if multi_pod else "pod",
@@ -205,6 +212,10 @@ def cell_list():
 
 
 def main():
+    # jax locks the device count when its backend first initialises, which
+    # nothing above has done yet
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -22,6 +22,8 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
 
 import jax
@@ -31,12 +33,28 @@ import numpy as np
 from repro.api.federation import Federation
 from repro.configs.base import ShapeConfig, get_arch, smoke_config
 from repro.ckpt.manager import CheckpointManager
-from repro.core.fl_step import build_fl_round_step, init_state, n_clients_for
+from repro.core.fl_step import (build_fl_round_step, client_axis_for,
+                                init_state, n_clients_for)
 from repro.core.stats import StatsSimulator
 from repro.core.topology import compile_tree, flat_schedule
 from repro.data.federated import FederatedTokens
 from repro.ft.failures import FailurePlan, demote_stragglers
 from repro.launch.mesh import make_host_mesh
+
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (jax reads it
+    itself).  Otherwise the cache lives at the fixed ``<checkout>/.jax_cache``
+    so that every run of this checkout finds what the last one compiled."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class SDFLMQTrainer:
@@ -46,6 +64,13 @@ class SDFLMQTrainer:
                  failure_plan: FailurePlan | None = None,
                  strategy: str = "fedavg",
                  update_filter=None):
+        # client i owns row i of the mesh's client axis: the counts must agree
+        mesh_clients = n_clients_for(cfg, mesh)
+        if n_clients != mesh_clients:
+            raise ValueError(
+                f"n_clients={n_clients} but the mesh holds {mesh_clients} "
+                f"client(s) on its {client_axis_for(cfg, mesh)!r} axis "
+                f"(mesh shape {dict(mesh.shape)})")
         self.cfg, self.mesh, self.rounds = cfg, mesh, rounds
         self.n = n_clients
         self.batch_per_client, self.seq = batch_per_client, seq
@@ -76,8 +101,10 @@ class SDFLMQTrainer:
 
         # ---- data plane ----------------------------------------------
         self.data = FederatedTokens(cfg.vocab, n_clients, seed=seed)
+        # the learning-rate schedule spans the whole run
+        self.total_steps = rounds * cfg.fl.local_steps
         self.state = init_state(cfg, mesh, jax.random.PRNGKey(seed),
-                                total_steps=rounds * cfg.fl.local_steps,
+                                total_steps=self.total_steps,
                                 update_filter=update_filter)
         self._compiled = {}
         self.ckpt = CheckpointManager(ckpt_dir, keep=2) if ckpt_dir else None
@@ -85,7 +112,9 @@ class SDFLMQTrainer:
         if self.ckpt:
             restored, meta = self.ckpt.restore_latest(like=self.state)
             if restored is not None:
-                self.state = jax.tree_util.tree_map(jnp.asarray, restored)
+                self.state = jax.tree_util.tree_map(
+                    lambda r, s: jax.device_put(r, s.sharding),
+                    restored, self.state)
                 self.start_round = int(meta["step"])
         self.metrics: list[dict] = []
         self.latencies: dict[str, float] = {}
@@ -103,10 +132,14 @@ class SDFLMQTrainer:
     def _step_for(self, schedule):
         key = schedule.signature()
         if key not in self._compiled:
+            # the state is donated: a step's output replaces it in place, so
+            # device memory holds one train state, not two
             self._compiled[key] = jax.jit(
                 build_fl_round_step(self.cfg, self.mesh, schedule,
+                                    total_steps=self.total_steps,
                                     strategy=self.strategy,
-                                    update_filter=self.update_filter))
+                                    update_filter=self.update_filter),
+                donate_argnums=0)
         return self._compiled[key]
 
     def run(self) -> list[dict]:
@@ -126,10 +159,13 @@ class SDFLMQTrainer:
             step = self._step_for(schedule)
             batch_np = self.data.global_batch(
                 self.n, self.batch_per_client, self.seq, r)
-            batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+            # one client: the model takes (batch, seq), with no clients axis
+            batch = {k: jnp.asarray(v if self.n > 1 else v[0])
+                     for k, v in batch_np.items()}
             with self.mesh:
                 self.state, m = step(self.state, batch,
                                      jnp.asarray(weights_np))
+            jax.block_until_ready(self.state)
             loss = float(m["loss"])
             dt = time.perf_counter() - t0
             self.metrics.append({"round": r, "loss": loss, "time_s": dt,
@@ -172,6 +208,7 @@ def main():
     ap.add_argument("--model-mesh", type=int, default=1)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

@@ -127,11 +127,8 @@ def _constrain_dim(x, dim, axis_name="model"):
     from jax.sharding import NamedSharding, PartitionSpec
     spec = [PartitionSpec.UNCONSTRAINED] * x.ndim
     spec[dim] = axis_name
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(m, PartitionSpec(*spec)))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(m, PartitionSpec(*spec)))
 
 
 def _nq_for(Sq, chunk_q):

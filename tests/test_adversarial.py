@@ -347,7 +347,8 @@ from repro.api.strategies import get_strategy
 from repro.core.aggregation import aggregate_params
 from repro.core.topology import flat_schedule
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)
 n = 4
 rng = np.random.default_rng(7)
 pw = rng.normal(size=(n, 8, 6)).astype(np.float32)

@@ -56,7 +56,8 @@ def _qagg_case(seed, shape):
 
 
 @pytest.mark.parametrize("shape", [(4, 64, 256), (3, 33, 7), (8, 1, 1024),
-                                   (1, 5, 5), (2, 128, 128)])
+                                   (1, 5, 5), (2, 128, 128),
+                                   (4, 600, 64), (2, 40, 5000)])
 def test_qagg_pallas_matches_ref_bit_exact(shape):
     import jax.numpy as jnp
     from repro.kernels.fedavg.ops import qagg

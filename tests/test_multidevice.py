@@ -39,7 +39,8 @@ from repro.core.fl_step import build_fl_round_step, init_state
 from repro.core.topology import AggSchedule, flat_schedule
 from repro.models import inputs as minputs
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)
 cfg = smoke_config(get_arch("hymba-1.5b"))
 shape = ShapeConfig("t", 32, 8, "train")
 key = jax.random.PRNGKey(0)
@@ -128,7 +129,8 @@ from repro.core.aggregation import aggregate_params
 from repro.core.clustering import build_tree
 from repro.core.topology import compile_tree, flat_schedule
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)
 n = 4
 rng = np.random.default_rng(0)
 params = {"w": jnp.asarray(rng.normal(size=(n, 8, 6)).astype(np.float32)),
@@ -185,7 +187,8 @@ from repro.api.strategies import get_strategy
 from repro.core.aggregation import aggregate_params
 from repro.core.topology import flat_schedule
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)
 n = 4
 rng = np.random.default_rng(1)
 pw = rng.normal(size=(n, 8, 6)).astype(np.float32)
