@@ -313,8 +313,9 @@ def _make_client_fn(cfg: ArchConfig, opt, local_steps: int,
             grads = jax.tree_util.tree_map(
                 lambda g, f: jnp.zeros_like(g) if f else g,
                 grads, frozen_mask)
-        updates, opt_state = opt.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params, step)
+            params = apply_updates(params, updates)
         return params, opt_state, loss
 
     def client_fn(params_c, opt_c, step, batch_c):
@@ -455,7 +456,8 @@ def build_fl_round_step(cfg: ArchConfig, mesh: Mesh, schedule: AggSchedule,
             # pre-round params double as the previous global (every client
             # starts a round from the identical aggregated model)
             ref = state["params"] if strat.needs_ref else None
-            params = _agg(params, weights, ref)
+            with jax.named_scope("aggregate"):
+                params = _agg(params, weights, ref)
             loss = jnp.mean(losses)
         else:
             params, opt_state, loss = client_fn(

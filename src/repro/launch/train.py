@@ -9,11 +9,20 @@ Wires the whole stack together:
                   round (local steps + hierarchical aggregation);
   substrate     — federated token streams (non-IID), checkpoint manager
                   (resume-exact), failure injection -> LWT -> role
-                  rearrangement, straggler demotion.
+                  rearrangement.
 
 Compiled steps are cached per schedule signature: a role rearrangement that
 reuses a previously-seen topology costs a dict lookup (the compiled-world
 analogue of the paper's "only affected clients re-subscribe").
+
+Each round runs inside a ``sdflmq.round`` profiler step (its number is the
+round's) and its host work inside ``repro.obs.host_span`` spans, in order:
+``schedule`` (failure injection, the schedule, the compiled-step lookup),
+``batch``, ``h2d`` (the batch's host->device copy), ``dispatch`` (the step
+call until it returns), ``wait`` (the state ready, the loss read back),
+``control_plane`` (the clients' ``signal_ready``) and, in rounds that save,
+``checkpoint``.  A profiler trace shows them beside the device's ops; each
+round's record holds their seconds as ``host_s``.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-7b --smoke \
@@ -38,8 +47,9 @@ from repro.core.fl_step import (build_fl_round_step, client_axis_for,
 from repro.core.stats import StatsSimulator
 from repro.core.topology import compile_tree, flat_schedule
 from repro.data.federated import FederatedTokens
-from repro.ft.failures import FailurePlan, demote_stragglers
+from repro.ft.failures import FailurePlan
 from repro.launch.mesh import make_host_mesh
+from repro.obs import host_span
 
 _CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
@@ -107,6 +117,7 @@ class SDFLMQTrainer:
                                 total_steps=self.total_steps,
                                 update_filter=update_filter)
         self._compiled = {}
+        self.step_compiles = 0          # misses of the compiled-step cache
         self.ckpt = CheckpointManager(ckpt_dir, keep=2) if ckpt_dir else None
         self.start_round = 0
         if self.ckpt:
@@ -117,7 +128,6 @@ class SDFLMQTrainer:
                     restored, self.state)
                 self.start_round = int(meta["step"])
         self.metrics: list[dict] = []
-        self.latencies: dict[str, float] = {}
 
     # ------------------------------------------------------------------
     def _schedule(self):
@@ -132,6 +142,7 @@ class SDFLMQTrainer:
     def _step_for(self, schedule):
         key = schedule.signature()
         if key not in self._compiled:
+            self.step_compiles += 1
             # the state is donated: a step's output replaces it in place, so
             # device memory holds one train state, not two
             self._compiled[key] = jax.jit(
@@ -143,12 +154,19 @@ class SDFLMQTrainer:
         return self._compiled[key]
 
     def run(self) -> list[dict]:
-        sid = self.sid
         weights_np = np.array(
             [self.clients[f"c{i}"].stats.samples or 1.0
              for i in range(self.n)], np.float32)
         for r in range(self.start_round, self.rounds):
-            t0 = time.perf_counter()
+            with jax.profiler.StepTraceAnnotation("sdflmq.round",
+                                                  step_num=r):
+                self._round(r, weights_np)
+        return self.metrics
+
+    def _round(self, r: int, weights_np: np.ndarray):
+        t0 = time.perf_counter()
+        host_s: dict[str, float] = {}
+        with host_span("schedule", host_s):
             # failure injection -> LWT -> coordinator rearranges; the dead
             # client's mesh row gets zero FedAvg weight (sums unaffected)
             for dead in self.failures.fail_at.get(r, []):
@@ -156,32 +174,41 @@ class SDFLMQTrainer:
                     self.session.fail(dead)
                     weights_np[int(dead[1:])] = 0.0
             schedule = self._schedule()
+            misses = self.step_compiles
             step = self._step_for(schedule)
+        with host_span("batch", host_s):
             batch_np = self.data.global_batch(
                 self.n, self.batch_per_client, self.seq, r)
+        with host_span("h2d", host_s):
             # one client: the model takes (batch, seq), with no clients axis
             batch = {k: jnp.asarray(v if self.n > 1 else v[0])
                      for k, v in batch_np.items()}
+        with host_span("dispatch", host_s):
             with self.mesh:
                 self.state, m = step(self.state, batch,
                                      jnp.asarray(weights_np))
+        with host_span("wait", host_s):
             jax.block_until_ready(self.state)
             loss = float(m["loss"])
-            dt = time.perf_counter() - t0
-            self.metrics.append({"round": r, "loss": loss, "time_s": dt,
-                                 "schedule": schedule.signature(),
-                                 "n_clients": len(self.clients)})
+        dt = time.perf_counter() - t0
+        self.metrics.append({"round": r, "loss": loss, "time_s": dt,
+                             "schedule": schedule.signature(),
+                             "n_clients": len(self.clients),
+                             "host_s": host_s,
+                             "compiled": self.step_compiles > misses,
+                             "h2d_bytes": sum(int(v.nbytes)
+                                              for v in batch.values())})
+        with host_span("control_plane", host_s):
             # round-status updates: stats + readiness -> role optimization
             slow = self.failures.straggle_at.get(r, {})
             for cid, cl in list(self.clients.items()):
                 st = self.sim.sample(cid, r + 1)
                 st.last_round_s = dt * slow.get(cid, 1.0)
                 st.samples = int(weights_np[int(cid[1:])])
-                self.latencies[cid] = st.last_round_s
-                cl.signal_ready(sid, stats=st)
-            if self.ckpt and self.ckpt.should_save(r + 1):
+                cl.signal_ready(self.sid, stats=st)
+        if self.ckpt and self.ckpt.should_save(r + 1):
+            with host_span("checkpoint", host_s):
                 self.ckpt.save(r + 1, self.state, {"loss": loss})
-        return self.metrics
 
 
 def main():
@@ -229,9 +256,11 @@ def main():
                             strategy=args.strategy,
                             update_filter=args.update_filter)
     for m in trainer.run():
+        split = " ".join(f"{k}={1e3 * v:.1f}" for k, v in m["host_s"].items())
         print(f"round {m['round']:3d} loss {m['loss']:.4f} "
               f"{m['time_s']:.2f}s sched={m['schedule']} "
-              f"clients={m['n_clients']}")
+              f"clients={m['n_clients']} "
+              f"{'compiled ' if m['compiled'] else ''}host_ms {split}")
 
 
 if __name__ == "__main__":

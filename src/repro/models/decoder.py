@@ -76,19 +76,21 @@ def cache_decl(cfg: ArchConfig, batch: int, cache_len: int):
 # --------------------------------------------------------------------------
 
 def _ffn(cfg: ArchConfig, lp, x, kind: str):
-    if kind == "moe":
-        return moe_apply(cfg, lp["moe"], x)
-    return swiglu(lp["mlp"], x), jnp.float32(0.0)
+    with jax.named_scope("mlp"):
+        if kind == "moe":
+            return moe_apply(cfg, lp["moe"], x)
+        return swiglu(lp["mlp"], x), jnp.float32(0.0)
 
 
 def _apply_layer(cfg: ArchConfig, lp, x, positions, kind: str,
                  return_kv: bool = False):
     h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-    q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg.rope_theta)
-    o = attn.attention(q, k, v, positions, positions, causal=True,
-                       window=cfg.window, chunk=cfg.attn_chunk,
-                       chunk_threshold=cfg.attn_chunk_threshold)
-    x = x + attn.project_out(lp["attn"], o)
+    with jax.named_scope("attention"):
+        q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg.rope_theta)
+        o = attn.attention(q, k, v, positions, positions, causal=True,
+                           window=cfg.window, chunk=cfg.attn_chunk,
+                           chunk_threshold=cfg.attn_chunk_threshold)
+        x = x + attn.project_out(lp["attn"], o)
     h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
     y, aux = _ffn(cfg, lp, h2, kind)
     x = x + y
@@ -156,8 +158,9 @@ def forward(cfg: ArchConfig, params, batch):
     kind = "moe" if cfg.moe else "dense"
     (x, a), _ = _scan_layers(cfg, params["layers"], x, positions, kind, False)
     aux += a
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return logits_out(params["embed"], x), aux
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return logits_out(params["embed"], x), aux
 
 
 def prefill(cfg: ArchConfig, params, batch):

@@ -124,8 +124,9 @@ def forward(cfg: ArchConfig, params, batch):
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, params["dec_layers"])
-    x = layernorm(params["final_norm"], x, cfg.norm_eps)
-    return logits_out(params["embed"], x), jnp.float32(0.0)
+    with jax.named_scope("lm_head"):
+        x = layernorm(params["final_norm"], x, cfg.norm_eps)
+        return logits_out(params["embed"], x), jnp.float32(0.0)
 
 
 def prefill(cfg: ArchConfig, params, batch):

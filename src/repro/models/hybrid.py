@@ -121,15 +121,18 @@ def _ssm_branch(cfg, sp, h, s0=None, conv_state=None, chunk=None):
 
 def _apply_layer(cfg, lp, x, positions):
     h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-    q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg.rope_theta)
-    o = attn.attention(q, k, v, positions, positions, causal=True,
-                       window=cfg.window, chunk=cfg.attn_chunk,
-                       chunk_threshold=cfg.attn_chunk_threshold)
-    a_out = attn.project_out(lp["attn"], o)
-    s_out, _, _ = _ssm_branch(cfg, lp["ssm"], h)
+    with jax.named_scope("attention"):
+        q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg.rope_theta)
+        o = attn.attention(q, k, v, positions, positions, causal=True,
+                           window=cfg.window, chunk=cfg.attn_chunk,
+                           chunk_threshold=cfg.attn_chunk_threshold)
+        a_out = attn.project_out(lp["attn"], o)
+    with jax.named_scope("ssm"):
+        s_out, _, _ = _ssm_branch(cfg, lp["ssm"], h)
     x = x + 0.5 * (a_out + s_out)
     h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
-    return x + swiglu(lp["mlp"], h2)
+    with jax.named_scope("mlp"):
+        return x + swiglu(lp["mlp"], h2)
 
 
 def forward(cfg: ArchConfig, params, batch):
@@ -142,8 +145,9 @@ def forward(cfg: ArchConfig, params, batch):
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return logits_out(params["embed"], x), jnp.float32(0.0)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return logits_out(params["embed"], x), jnp.float32(0.0)
 
 
 def prefill(cfg: ArchConfig, params, batch):

@@ -63,5 +63,6 @@ def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 def loss_fn(cfg: ArchConfig, params, batch):
     logits, aux = get_model(cfg).forward(cfg, params, batch)
-    ce = cross_entropy(logits, batch["labels"])
+    with jax.named_scope("lm_head"):
+        ce = cross_entropy(logits, batch["labels"])
     return ce + aux, {"ce": ce, "aux": aux}

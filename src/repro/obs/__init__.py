@@ -12,6 +12,8 @@ operational surface:
     train/contribute/flush/mint/partition/heal/...) with virtual-or-wall
     timestamps in a bounded ring buffer, exportable as JSON timelines,
   * :func:`serve_metrics` — a one-liner stdlib-HTTP ``/metrics`` endpoint,
+  * :func:`host_span` — a span of the compiled trainer's round loop on the
+    JAX profiler's clock, timed into a per-round dict,
   * :class:`Telemetry` — the facade ``Federation(metrics=...)`` wires
     through the whole stack (pull collectors over every component's
     existing stats surface + push hooks at control-plane event points).
@@ -19,7 +21,9 @@ operational surface:
 Everything is opt-in: with ``Federation(metrics=None)`` (the default) no
 object from this package is ever constructed and the hot paths take the
 exact pre-telemetry branches, so the zero-overhead default stays
-bit-identical.
+bit-identical.  The one exception is ``host_span``: the compiled trainer
+always opens its spans, which the JAX profiler records only while it
+traces.
 """
 from __future__ import annotations
 
@@ -27,11 +31,12 @@ from repro.obs.exporters import (render_prom, serve_metrics, timeline_json,
                                  write_timeline_json)
 from repro.obs.instrument import SYS_CORE, Telemetry
 from repro.obs.registry import MetricsRegistry
-from repro.obs.tracer import Tracer
+from repro.obs.tracer import Tracer, host_span
 
 __all__ = [
     "MetricsRegistry",
     "Tracer",
+    "host_span",
     "Telemetry",
     "SYS_CORE",
     "render_prom",

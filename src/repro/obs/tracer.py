@@ -8,15 +8,35 @@ clock to fall back to wall time (``time.time()``).
 
 The ring is bounded (``maxlen``): old events are dropped, never the run.
 ``dropped`` counts what fell off so exports can flag truncation.
+
+:func:`host_span` is the compiled trainer's span: a named interval of host
+work on the JAX profiler's clock, so that a profiler trace shows it on the
+same timeline as the device's operations.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
-__all__ = ["Tracer"]
+__all__ = ["Tracer", "host_span"]
+
+SPAN_PREFIX = "sdflmq."
+
+
+@contextlib.contextmanager
+def host_span(name: str, into: Dict[str, float]) -> Iterator[None]:
+    """Host work named ``name``: a ``jax.profiler.TraceAnnotation`` named
+    ``sdflmq.<name>`` (recorded only while the profiler traces), whose
+    ``time.perf_counter()`` seconds are added to ``into[name]``."""
+    import jax
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+        t0 = time.perf_counter()
+        yield
+        into[name] = into.get(name, 0.0) + time.perf_counter() - t0
+
 
 # Noisy data-plane kinds excluded from compact timelines by default.
 NOISY_KINDS = ("publish", "deliver")

@@ -220,3 +220,27 @@ for name in ("trimmed_mean", "coordinate_median"):
 print("MASKED ROBUST COMBINE OK")
 '''
     assert "MASKED ROBUST COMBINE OK" in run_sub(code)
+
+
+def test_two_client_round_step_scopes_its_aggregation():
+    code = '''
+import re
+import jax, jax.numpy as jnp
+from repro.configs.base import get_arch, smoke_config
+from repro.launch.mesh import make_host_mesh
+from repro.launch.train import SDFLMQTrainer
+
+cfg = smoke_config(get_arch("qwen1.5-4b"))
+tr = SDFLMQTrainer(cfg, make_host_mesh(data=2, model=1), 2, 1, 2, 32,
+                   schedule_kind="flat")
+step = tr._step_for(tr._schedule())
+batch = {k: jnp.asarray(v) for k, v in tr.data.global_batch(2, 2, 32, 0).items()}
+with tr.mesh:
+    text = step.lower(tr.state, batch, jnp.ones((2,))).compile().as_text()
+segments = [set(re.sub(r"[^/()]*\\(|\\)", "/", n).split("/"))
+            for n in re.findall(r'op_name="([^"]*)"', text)]
+for scope in ("aggregate", "optimizer", "attention", "mlp", "lm_head"):
+    assert any(scope in s for s in segments), scope
+print("AGGREGATE SCOPED")
+'''
+    assert "AGGREGATE SCOPED" in run_sub(code, devices=2)
