@@ -19,9 +19,21 @@ Two implementations:
     (all exponents of non-positive numbers -> numerically safe), inter-chunk
     via the carried state.  O(T/C) sequential steps, O(C^2) parallel work —
     this is the TPU-friendly form the Pallas kernel (kernels/wkv6) mirrors.
+
+``chunked`` takes one of two forms of its chunk step, chosen from the
+input's shape alone:
+  * scalar decay (``w_log.shape[-1] == 1`` and ``u is None``; Hymba): the
+    decay factor exp(cum_t - cum_s) is the same for every key channel, so
+    the intra-chunk score is (r_t . k_s) exp(cum_t - cum_s) — a matmul times
+    one (B, H, C, C) decay matrix per chunk;
+  * per channel (a (B, T, H, d_k) decay or a ``u`` bonus; RWKV6): the
+    decay differs per key channel, so each chunk builds the pairwise
+    (B, C, C, H, d_k) decay tensor and contracts it with r and k.
+``form_counts()`` tallies, at trace time, which form each call took.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Optional
 
 import jax
@@ -81,13 +93,25 @@ def decode_step(r, k, v, w_log, S, u: Optional[jax.Array] = None):
 # Chunked (production path; Pallas kernel mirrors this)
 # --------------------------------------------------------------------------
 
+_FORMS: Counter = Counter()
+
+
+def form_counts() -> dict:
+    """How many ``chunked`` calls traced in this process took each form:
+    ``{"scalar_decay": n, "per_channel": m}`` (absent keys are 0)."""
+    return dict(_FORMS)
+
+
 def chunked(r, k, v, w_log, u: Optional[jax.Array] = None, s0=None,
             chunk: int = 64):
     """Same contract as ``recurrent``; mathematically identical."""
     B, T, H, dk = r.shape
     dv = v.shape[-1]
     C = min(chunk, T)
-    w_log = _bcast_w(w_log, r.shape).astype(jnp.float32)
+    scalar = w_log.shape[-1] == 1 and u is None
+    _FORMS["scalar_decay" if scalar else "per_channel"] += 1
+    w_log = _bcast_w(w_log, (B, T, H, 1) if scalar else r.shape
+                     ).astype(jnp.float32)
     rf, kf, vf = (a.astype(jnp.float32) for a in (r, k, v))
     T_orig = T
     if T % C:
@@ -114,6 +138,13 @@ def chunked(r, k, v, w_log, u: Optional[jax.Array] = None, s0=None,
         if a.shape[h_dim] % msize == 0:
             return _constrain_dim(a, h_dim)
         return _constrain_dim(a, b_dim)
+
+    if scalar:
+        if s0 is None:
+            s0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+        s_fin, o = _scalar_decay_scan(rf, kf, vf, w_log[..., 0],
+                                      _pin(s0, 1, 0), C, _pin)
+        return o[:, :T_orig], s_fin
 
     def to_chunks(a, last):
         return a.reshape(B, n, C, H, last).transpose(1, 0, 2, 3, 4)
@@ -162,6 +193,48 @@ def chunked(r, k, v, w_log, u: Optional[jax.Array] = None, s0=None,
                              s0, (rc, kc, vc, wc))
     o = oc.transpose(1, 0, 2, 3, 4).reshape(B, T, H, dv)[:, :T_orig]
     return o, s_fin
+
+
+def _scalar_decay_scan(rf, kf, vf, w, s0, C, pin):
+    """The SSD chunk scan for one decay per head: rf, kf (B,T,H,dk), vf
+    (B,T,H,dv), w (B,T,H), T a multiple of C.  Chunks are laid out
+    (B, H, C, d), so the decay matrix L and the scores A are (B, H, C, C)
+    with the chunk's two time axes minor.  Returns (s_final, o (B,T,H,dv))."""
+    B, T, H, dk = rf.shape
+    dv = vf.shape[-1]
+    n = T // C
+
+    def to_chunks(a):                              # (B,T,H,...) -> (n,B,H,C,...)
+        a = a.reshape(B, n, C, H, *a.shape[3:])
+        return pin(jnp.moveaxis(a, (1, 3), (0, 2)), 2, 1)
+
+    idx = jnp.arange(C)
+    causal = idx[:, None] >= idx[None, :]          # inclusive: o_t = r_t^T S_t
+
+    def chunk_step(S, inp):
+        rb, kb, vb, wb = inp                       # (B,H,C,d*), wb (B,H,C)
+        cum = jnp.cumsum(wb, axis=-1)              # inclusive log-decay
+        # ---- inter-chunk: state contribution -------------------------
+        o_inter = jnp.einsum("bhtk,bhkv->bhtv", rb * jnp.exp(cum)[..., None],
+                             S)
+        # ---- intra-chunk: L[t,s] = exp(cum_t - cum_s) for s <= t ----------
+        # masked before the exp, so neither value nor gradient meets inf*0
+        diff = jnp.where(causal, cum[..., :, None] - cum[..., None, :],
+                         -jnp.inf)                 # (B,H,C,C)
+        A = jnp.einsum("bhtk,bhsk->bhts", rb, kb) * jnp.exp(diff)
+        o_intra = jnp.einsum("bhts,bhsv->bhtv", A, vb)
+        # ---- state update --------------------------------------------
+        cum_last = cum[..., -1:]                   # (B,H,1)
+        k_eff = kb * jnp.exp(cum_last - cum)[..., None]
+        S_new = S * jnp.exp(cum_last)[..., None] + jnp.einsum(
+            "bhck,bhcv->bhkv", k_eff, vb)
+        return S_new, o_inter + o_intra
+
+    # remat the chunk step as the per-channel form does: only the carried
+    # state (B,H,dk,dv) is stacked across steps for the backward.
+    s_fin, oc = jax.lax.scan(jax.checkpoint(chunk_step, prevent_cse=False),
+                             s0, tuple(to_chunks(a) for a in (rf, kf, vf, w)))
+    return s_fin, jnp.moveaxis(oc, (0, 2), (1, 3)).reshape(B, T, H, dv)
 
 
 def linear_attention(r, k, v, w_log, u=None, s0=None, chunk: int = 64,
