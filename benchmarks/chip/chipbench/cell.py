@@ -17,13 +17,14 @@ from dataclasses import dataclass, field
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 CHECKOUT = BENCH_DIR.parents[1]
 
-# ArchConfig fields a configuration file may set under "model": the
-# architecture and its numerics.  Implementation knobs (chunk sizes, remat)
-# stay the program's own, so an optimisation can change them.
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab", "window", "ssm_state", "rope_theta",
-              "norm_eps", "qkv_bias", "tie_embeddings", "dtype",
-              "param_dtype", "optimizer")
+# Implementation knobs of the program's ``ArchConfig`` and ``MoEConfig``
+# (chunk sizes, remat, dispatch, the federation settings and the registry's
+# own labels): they stay the program's, so an optimisation can change them.
+# A configuration file's "model" may set every other field, and its nested
+# "moe" every other field of ``MoEConfig``.
+KNOBS = ("attn_chunk", "attn_chunk_threshold", "rwkv_chunk", "remat", "fl",
+         "name", "family", "notes")
+MOE_KNOBS = ("capacity_factor", "impl", "router_jitter")
 
 TRAFFIC_KEYS = ("clients", "batch_per_client", "seq", "local_steps",
                 "schedule", "role_policy", "strategy", "rounds",
@@ -63,10 +64,7 @@ def load_cell(workload: str, bench_dir: pathlib.Path = BENCH_DIR,
     for key in ("arch", "family", "model", "optimizer"):
         if key not in config:
             raise ValueError(f"configs/{w['config']}.json lacks {key!r}")
-    bad = sorted(set(config["model"]) - set(MODEL_KEYS))
-    if bad:
-        raise ValueError(f"configs/{w['config']}.json sets {bad}, which "
-                         f"are not architecture keys {MODEL_KEYS}")
+    _check_model(config["model"], f"configs/{w['config']}.json")
     missing = [k for k in TRAFFIC_KEYS if k not in traffic]
     if missing:
         raise ValueError(f"traffic/{w['traffic']}.json lacks {missing}")
@@ -79,14 +77,44 @@ def load_cell(workload: str, bench_dir: pathlib.Path = BENCH_DIR,
                 [m for m in spec["end_to_end"] if applies(m)])
 
 
+def _architecture_keys():
+    """The ``ArchConfig`` and ``MoEConfig`` fields a configuration may set."""
+    import dataclasses
+    from repro.configs.base import ArchConfig, MoEConfig
+    arch = {f.name for f in dataclasses.fields(ArchConfig)}
+    moe = {f.name for f in dataclasses.fields(MoEConfig)}
+    return arch - set(KNOBS), moe - set(MOE_KNOBS)
+
+
+def _check_model(model: dict, where: str):
+    arch_keys, moe_keys = _architecture_keys()
+    bad = sorted(set(model) - arch_keys)
+    moe = model.get("moe")
+    if moe is not None:
+        if not isinstance(moe, dict):
+            raise ValueError(f"{where} sets 'moe' to {moe!r}, not an object")
+        bad += [f"moe.{k}" for k in sorted(set(moe) - moe_keys)]
+    if bad:
+        raise ValueError(f"{where} sets {bad}, which are not architecture "
+                         f"keys (knobs {KNOBS}, moe knobs {MOE_KNOBS}, or "
+                         f"not fields of the program's configuration)")
+
+
 def arch_config(cell: Cell):
     """The program's ``ArchConfig`` for this cell: the registered
-    architecture with every key of the configuration file applied, and the
-    traffic's federation settings."""
+    architecture with every key of the configuration file applied (the
+    nested ``moe`` onto the registered ``MoEConfig``, or a new one where the
+    architecture has none), and the traffic's federation settings."""
     import dataclasses
-    from repro.configs.base import get_arch
+    from repro.configs.base import MoEConfig, get_arch
     t = cell.traffic
-    cfg = get_arch(cell.config["arch"]).replace(**cell.config["model"])
+    model = dict(cell.config["model"])
+    cfg = get_arch(cell.config["arch"])
+    if model.get("moe") is not None:
+        moe = model.pop("moe")
+        model["moe"] = (dataclasses.replace(cfg.moe, **moe) if cfg.moe
+                        else MoEConfig(**moe))
+    cfg = cfg.replace(**model)
     fl = dataclasses.replace(cfg.fl, local_steps=t["local_steps"],
                              schedule=t["schedule"],
                              role_policy=t["role_policy"])

@@ -250,9 +250,11 @@ def build(cell, seed: int, devs):
         trainer.state["params"])
     shardings = jax.tree_util.tree_map(lambda a: a.sharding,
                                        trainer.state["params"])
+    stacked = _stacked(cfg)
     trainer.state["params"] = None
-    trainer.state["params"] = weights.make_on_device(seed, like, shardings, n)
-    return trainer, (lambda key: weights.make(key, like, n))
+    trainer.state["params"] = weights.make_on_device(seed, like, shardings, n,
+                                                     stacked)
+    return trainer, (lambda key: weights.make(key, like, n, stacked))
 
 
 def first_rounds(trainer, rc, cell, seed, params0):
@@ -293,16 +295,22 @@ def reference(cell, seed: int, devs, batches, weights, precision=None,
 def _one_client(jax, seed, device, cell):
     """The starting weights of one client, on ``device``."""
     from jax.sharding import SingleDeviceSharding
-    like = _param_like(jax, cell)
-    sh = jax.tree_util.tree_map(lambda _: SingleDeviceSharding(device), like)
-    return weights.make_on_device(seed, like, sh, 1)
-
-
-def _param_like(jax, cell):
-    from repro.models import model_api
     cfg = arch_config(cell)
+    like = _param_like(jax, cfg)
+    sh = jax.tree_util.tree_map(lambda _: SingleDeviceSharding(device), like)
+    return weights.make_on_device(seed, like, sh, 1, _stacked(cfg))
+
+
+def _param_like(jax, cfg):
+    from repro.models import model_api
     return jax.eval_shape(lambda: model_api.init_params(
         cfg, jax.random.PRNGKey(0)))
+
+
+def _stacked(cfg) -> dict:
+    """Each leaf's leading stacking axes, from the program's declarations."""
+    from repro.models import model_api
+    return weights.stacking(model_api.param_decls(cfg))
 
 
 def _write_rounds(out_dir, workload, seed, trace, info, losses, checked):

@@ -113,10 +113,19 @@ def swiglu(Q, p, x):
     return mm(Q, "bsf,fd->bsd", jax.nn.silu(g) * u, p["w_down"])
 
 
-def head_loss(Q, final_scale, out_table, x, labels, eps):
-    """Sum over tokens of the cross-entropy, over every row of the
-    output table."""
-    logits = mm(Q, "bsd,vd->bsv", rmsnorm(x, final_scale, eps), out_table)
+def blocks(params) -> list:
+    """The forward pass of a family with one uniform stack: every layer of
+    ``layers`` in order (its depth read from ``ln1``), of no kind."""
+    return [("layers", i, None)
+            for i in range(params["layers"]["ln1"]["scale"].shape[0])]
+
+
+def head_loss(Q, top, x, labels, model):
+    """Sum over tokens of the cross-entropy of the RMSNorm head over the
+    untied output table ``embed/out_table``, over every row of the table."""
+    logits = mm(Q, "bsd,vd->bsv",
+                rmsnorm(x, top["final_norm"]["scale"], model["norm_eps"]),
+                top["embed"]["out_table"])
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     return jnp.sum(lse - picked)

@@ -5,7 +5,7 @@ from __future__ import annotations
 from chipbench.ref_common import attention, dense_block_flops, rmsnorm, swiglu
 
 
-def layer(Q, lp, x, model):
+def layer(Q, lp, x, model, kind=None):
     eps = model["norm_eps"]
     h = rmsnorm(x, lp["ln1"]["scale"], eps)
     x = x + attention(Q, lp["attn"], h, model["rope_theta"], model["window"])

@@ -51,7 +51,7 @@ def ssm(Q, p, h):
     return mm(Q, "bshk,hkd->bsd", y, p["out_w"])
 
 
-def layer(Q, lp, x, model):
+def layer(Q, lp, x, model, kind=None):
     eps = model["norm_eps"]
     h = rmsnorm(x, lp["ln1"]["scale"], eps)
     a = attention(Q, lp["attn"], h, model["rope_theta"], model["window"])

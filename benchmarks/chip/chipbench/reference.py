@@ -1,7 +1,10 @@
 """The plain reference trainer: the configuration's model and optimizer in
 float32, driven over the same weights and batches as the program's first
 rounds, layer by layer and one batch row at a time so that it fits the
-chip once the program's state is freed.
+chip once the program's state is freed.  The family's module says which
+layers the forward pass takes and in what order (``Reference``); leaves
+are named ``<top-level key>/<leaf>`` and summed over each stack, as the
+program's leaves are.
 
 It imports nothing of the program.  Parameters are stored in the type the
 configuration states (bfloat16 matrices, float32 norms and state-space
@@ -40,8 +43,19 @@ def lr_at(opt: dict, total_steps: int, step: int) -> float:
 
 
 class Reference:
-    """One client's model and AdamW state, in the reference's layout:
-    ``layers`` is a list with one dict per layer."""
+    """One client's model and AdamW state, in the reference's layout: each
+    stack of layers that the family's ``blocks`` walks is a list with one
+    dict per layer; every other top-level key (``embed``, ``final_norm``)
+    is a dict as the program has it.
+
+    A family module ``ref_<family>`` defines ``layer(Q, lp, x, model,
+    kind)`` and ``flops_per_token``, and may define ``blocks(params)`` (the
+    ordered ``(stack key, index, kind)`` the forward pass takes),
+    ``head_loss(Q, top, x, labels, model)`` and ``embed_in(top, tokens,
+    model)``.  Where it leaves one out: one stack ``layers``
+    (``ref_common.blocks``), an RMSNorm head over an untied output table
+    (``ref_common.head_loss``), and a lookup in ``embed/in_table`` whose
+    gradient is scattered back in float32."""
 
     def __init__(self, config: dict, params, total_steps: int,
                  precision=None):
@@ -53,14 +67,16 @@ class Reference:
         Q = ref_common.Float8() if precision == "float8" else ref_common.EXACT
         mod = family(config["family"])
         model = self.model
-        eps = model["norm_eps"]
         self.device = next(iter(jax.tree_util.tree_leaves(params)[0].devices()))
-        n_layers = params["layers"]["ln1"]["scale"].shape[0]
-        self.params = {
-            "embed": params["embed"], "final_norm": params["final_norm"],
-            "layers": [jax.tree_util.tree_map(lambda a: a[i],
-                                              params["layers"])
-                       for i in range(n_layers)]}
+        self.blocks = getattr(mod, "blocks", ref_common.blocks)(params)
+        self.stacks = list(dict.fromkeys(key for key, _, _ in self.blocks))
+        self.params = {k: v for k, v in params.items()
+                       if k not in self.stacks}
+        for key in self.stacks:
+            depth = jax.tree_util.tree_leaves(params[key])[0].shape[0]
+            self.params[key] = [
+                jax.tree_util.tree_map(lambda a, i=i: a[i], params[key])
+                for i in range(depth)]
         zeros = lambda t: jax.tree_util.tree_map(
             lambda a: jax.device_put(jnp.zeros(a.shape, F32), self.device), t)
         self.m = zeros(self.params)
@@ -69,27 +85,39 @@ class Reference:
         def up(t):
             return jax.tree_util.tree_map(lambda a: a.astype(F32), t)
 
-        def layer_fn(lp, x):
+        def layer_fn(lp, x, kind):
             with jax.default_matmul_precision("highest"):
-                return mod.layer(Q, up(lp), x, model)
+                return mod.layer(Q, up(lp), x, model, kind)
 
-        def layer_bwd(lp, x, dy):
-            _, vjp = jax.vjp(layer_fn, lp, x)
+        def layer_bwd(lp, x, dy, kind):
+            _, vjp = jax.vjp(lambda lp, x: layer_fn(lp, x, kind), lp, x)
             return vjp(dy)
 
-        def head(fs, ot, x, labels):
-            def f(fs, ot, x):
+        head_loss = getattr(mod, "head_loss", ref_common.head_loss)
+
+        def head(top, x, labels):
+            def f(top, x):
                 with jax.default_matmul_precision("highest"):
-                    return ref_common.head_loss(Q, fs.astype(F32),
-                                                ot.astype(F32), x, labels,
-                                                eps)
-            return jax.value_and_grad(f, argnums=(0, 1, 2))(fs, ot, x)
+                    return head_loss(Q, up(top), x, labels, model)
+            return jax.value_and_grad(f, argnums=(0, 1))(top, x)
 
-        def embed_in(table, tokens):
-            return jnp.take(table, tokens, axis=0).astype(F32)
+        embed_in = getattr(mod, "embed_in", None)
+        if embed_in is None:
+            def embed_fwd(top, tokens):
+                return jnp.take(top["embed"]["in_table"], tokens,
+                                axis=0).astype(F32)
 
-        def embed_grad(shape, tokens, dx):
-            return jnp.zeros(shape, F32).at[tokens].add(dx)
+            def embed_bwd(top, tokens, dx):
+                shape = top["embed"]["in_table"].shape
+                return {"embed": {"in_table": jnp.zeros(shape, F32)
+                                  .at[tokens].add(dx)}}
+        else:
+            def embed_fwd(top, tokens):
+                return embed_in(up(top), tokens, model)
+
+            def embed_bwd(top, tokens, dx):
+                _, vjp = jax.vjp(lambda t: embed_fwd(t, tokens), top)
+                return vjp(dx)[0]
 
         b1, b2 = self.opt["b1"], self.opt["b2"]
         eps_a, wd = self.opt["eps"], self.opt["wd"]
@@ -107,12 +135,15 @@ class Reference:
                 lambda o: o[i], out, is_leaf=lambda o: isinstance(o, tuple))
             return pick(0), pick(1), pick(2)
 
-        self._layer = jax.jit(layer_fn)
-        self._layer_bwd = jax.jit(layer_bwd)
+        self._layer = jax.jit(layer_fn, static_argnums=2)
+        self._layer_bwd = jax.jit(layer_bwd, static_argnums=3)
         self._head = jax.jit(head)
-        self._embed_in = jax.jit(embed_in)
-        self._embed_grad = jax.jit(embed_grad, static_argnums=0)
+        self._embed_in = jax.jit(embed_fwd)
+        self._embed_grad = jax.jit(embed_bwd)
         self._adam = jax.jit(adam)
+
+    def _top(self) -> dict:
+        return {k: v for k, v in self.params.items() if k not in self.stacks}
 
     def _update(self, key, idx, grads, lr, t):
         if idx is None:
@@ -133,59 +164,63 @@ class Reference:
         tokens, labels = np.asarray(batch["tokens"]), np.asarray(batch["labels"])
         rows, n_tok = tokens.shape[0], tokens.size
         P = self.params
+        top = self._top()
         lr = lr_at(self.opt, self.total_steps, self.step)
         t = self.step + 1
         xs = []
         for r in range(rows):
-            x = self._embed_in(P["embed"]["in_table"], tokens[r:r + 1])
+            x = self._embed_in(top, tokens[r:r + 1])
             hist = [x]
-            for lp in P["layers"]:
-                x = self._layer(lp, x)
+            for key, i, kind in self.blocks:
+                x = self._layer(P[key][i], x, kind)
                 hist.append(x)
             xs.append(hist)
-        loss, g_fs, g_ot, dxs = 0.0, None, None, []
+        loss, g_top, dxs = 0.0, None, []
         for r in range(rows):
-            val, (dfs, dot, dx) = self._head(
-                P["final_norm"]["scale"], P["embed"]["out_table"],
-                xs[r][-1], labels[r:r + 1])
+            val, (dtop, dx) = self._head(top, xs[r][-1], labels[r:r + 1])
             loss = loss + val
-            g_fs = dfs if g_fs is None else g_fs + dfs
-            g_ot = dot if g_ot is None else g_ot + dot
+            g_top = dtop if g_top is None else jax.tree_util.tree_map(
+                jnp.add, g_top, dtop)
             dxs.append(dx)
-        for i in reversed(range(len(P["layers"]))):
+        for j in reversed(range(len(self.blocks))):
+            key, i, kind = self.blocks[j]
             g = None
             for r in range(rows):
-                dlp, dxs[r] = self._layer_bwd(P["layers"][i], xs[r][i],
-                                              dxs[r])
+                dlp, dxs[r] = self._layer_bwd(P[key][i], xs[r][j], dxs[r],
+                                              kind)
                 g = dlp if g is None else jax.tree_util.tree_map(
                     jnp.add, g, dlp)
-                xs[r][i + 1] = None
+                xs[r][j + 1] = None
             g = jax.tree_util.tree_map(lambda a: a / n_tok, g)
-            self._update("layers", i, g, lr, t)
-        shape = P["embed"]["in_table"].shape
-        g_in = sum(self._embed_grad(shape, tokens[r:r + 1], dxs[r])
-                   for r in range(rows))
-        self._update("embed", None, {"in_table": g_in / n_tok,
-                                     "out_table": g_ot / n_tok}, lr, t)
-        self._update("final_norm", None, {"scale": g_fs / n_tok}, lr, t)
+            self._update(key, i, g, lr, t)
+        for r in range(rows):
+            _add_into(g_top, self._embed_grad(top, tokens[r:r + 1], dxs[r]))
+        for key in top:
+            self._update(key, None, jax.tree_util.tree_map(
+                lambda a: a / n_tok, g_top[key]), lr, t)
         self.step += 1
         return loss / n_tok
+
+    def _walk(self, tree):
+        """``(program leaf name, layer index or None, array)`` of every
+        leaf of ``tree`` (the reference's layout)."""
+        for key, sub in tree.items():
+            if key in self.stacks:
+                for i, lp in enumerate(sub):
+                    for path, a in jax.tree_util.tree_flatten_with_path(lp)[0]:
+                        yield f"{key}/{leaf_name(path)}", i, a
+            else:
+                for path, a in jax.tree_util.tree_flatten_with_path(sub)[0]:
+                    yield f"{key}/{leaf_name(path)}", None, a
 
     def sq_norms(self, which: str) -> dict:
         """Squared norm of each leaf of the parameters (``params``) or of
         AdamW's first moment (``m``), named and stacked as the program's
-        leaves are."""
-        tree = getattr(self, which)
+        leaves are: each stack's layers summed."""
         out = {}
-        for key in ("embed", "final_norm"):
-            for path, a in jax.tree_util.tree_flatten_with_path(tree[key])[0]:
-                out[f"{key}/{leaf_name(path)}"] = float(
-                    jnp.sum(jnp.square(a.astype(F32))))
-        for lp in tree["layers"]:
-            for path, a in jax.tree_util.tree_flatten_with_path(lp)[0]:
-                name = f"layers/{leaf_name(path)}"
-                out[name] = out.get(name, 0.0) + float(
-                    jnp.sum(jnp.square(a.astype(F32))))
+        for name, _, a in self._walk(getattr(self, which)):
+            out[name] = out.get(name, 0.0) + float(
+                jnp.sum(jnp.square(a.astype(F32))))
         return out
 
     def delta_sq_norms(self, params0) -> dict:
@@ -196,19 +231,23 @@ class Reference:
                  jax.tree_util.tree_flatten_with_path(params0)[0]}
         diff = jax.jit(lambda a, b: jnp.sum(jnp.square(
             a.astype(F32) - b.astype(F32))))
-        for key in ("embed", "final_norm"):
-            for path, a in jax.tree_util.tree_flatten_with_path(
-                    self.params[key])[0]:
-                name = f"{key}/{leaf_name(path)}"
-                out[name] = float(diff(a, flat0[name]))
-        for i, lp in enumerate(self.params["layers"]):
-            for path, a in jax.tree_util.tree_flatten_with_path(lp)[0]:
-                name = f"layers/{leaf_name(path)}"
-                out[name] = out.get(name, 0.0) + float(diff(a, flat0[name][i]))
+        for name, i, a in self._walk(self.params):
+            b = flat0[name] if i is None else flat0[name][i]
+            out[name] = out.get(name, 0.0) + float(diff(a, b))
         return out
 
     def free_moments(self):
         self.m = self.v = None
+
+
+def _add_into(acc: dict, more: dict):
+    """Adds the leaves of ``more`` (a part of ``acc``'s tree) into
+    ``acc``."""
+    for k, v in more.items():
+        if isinstance(v, dict):
+            _add_into(acc[k], v)
+        else:
+            acc[k] = acc[k] + v
 
 
 def fedavg(refs: list, weights) -> None:
@@ -225,15 +264,14 @@ def fedavg(refs: list, weights) -> None:
                   for x, wi in zip(leaves, w))
         return acc.astype(leaves[0].dtype)
 
-    for key in ("embed", "final_norm"):
-        avg = jax.tree_util.tree_map(mean, *[r.params[key] for r in refs])
-        for r in refs:
-            r.params[key] = jax.device_put(avg, r.device)
-    for i in range(len(refs[0].params["layers"])):
-        avg = jax.tree_util.tree_map(
-            mean, *[r.params["layers"][i] for r in refs])
-        for r in refs:
-            r.params["layers"][i] = jax.device_put(avg, r.device)
+    # a stack is averaged one layer at a time, so that it fits
+    for key in refs[0].params:
+        stacked = key in refs[0].stacks
+        holders = [r.params[key] if stacked else r.params for r in refs]
+        for slot in range(len(holders[0])) if stacked else [key]:
+            avg = jax.tree_util.tree_map(mean, *[h[slot] for h in holders])
+            for r, h in zip(refs, holders):
+                h[slot] = jax.device_put(avg, r.device)
 
 
 def follow(config: dict, params: list, batches: list, local_steps: int,

@@ -6,6 +6,15 @@ the benchmark's own, so the reference takes no weight the program made.
 Every client starts from the same weights, as a federation does.  One
 jitted call makes the whole tree on the device, in the type it trains in,
 with exact arithmetic, so that the weights can be made again bit for bit.
+
+A matrix's spread is one over the square root of its fan-in: the size of
+the input axis its product contracts (two axes for ``wo`` and ``out_w``),
+counted past every leading axis that stacks layers or experts.  Which axes
+stack is the program's own declaration (``stacking`` of its
+``param_decls``: leading logical axes named ``layers`` or ``experts``), so
+an expert leaf (layers, experts, d, f) contracts d, and every stack of
+layers counts, whatever its name.  A layout given by shapes alone (no
+declarations) counts one stacking axis on the leaves under ``layers/``.
 """
 from __future__ import annotations
 
@@ -19,6 +28,8 @@ import jax.numpy as jnp
 _NEAR_ONE = {"scale": 0.1, "gn_scale": 0.1, "D_skip": 0.1}
 # contraction over the two axes before the output axis
 _FAN_IN_TWO = {"wo", "out_w"}
+# logical axes that stack layers or experts ahead of a leaf's own axes
+STACK_AXES = ("layers", "experts")
 # spread of the odd integers in [-127, 127] that _uniform draws
 _INT_STD = 73.9
 
@@ -39,7 +50,20 @@ def _uniform(key, shape, std: float):
     return k.astype(jnp.float32) * step
 
 
-def _leaf(name: str, shape, dtype, key, stacked: bool):
+def stacking(decls) -> dict:
+    """``{leaf name: leading axes that stack layers or experts}`` of the
+    program's parameter declarations (``model_api.param_decls``)."""
+    def lead(d):
+        n = 0
+        while n < len(d.axes) and d.axes[n] in STACK_AXES:
+            n += 1
+        return n
+    flat = jax.tree_util.tree_flatten_with_path(
+        decls, is_leaf=lambda x: hasattr(x, "axes"))[0]
+    return {leaf_name(p): lead(d) for p, d in flat}
+
+
+def _leaf(name: str, shape, dtype, key, lead: int):
     last = name.rsplit("/", 1)[-1]
     if last in _NEAR_ONE:
         x = 1.0 + _uniform(key, shape, _NEAR_ONE[last])
@@ -54,7 +78,7 @@ def _leaf(name: str, shape, dtype, key, stacked: bool):
     elif last == "in_table":
         x = _uniform(key, shape, 1.0)
     else:
-        dims = shape[1:] if stacked else shape
+        dims = shape[lead:]
         if last == "out_table":
             fan_in = dims[-1]
         elif last in _FAN_IN_TWO:
@@ -72,9 +96,10 @@ def seed_key(seed: int):
                               (seed >> 32) % (1 << 31))
 
 
-def make(key, like, n_clients: int = 1):
+def make(key, like, n_clients: int = 1, stacked: dict | None = None):
     """Values for the parameter tree ``like`` (arrays or shape structs)
-    from ``key`` (``seed_key``).
+    from ``key`` (``seed_key``), with ``stacked`` (``stacking``) giving each
+    leaf's leading stacking axes.
 
     With ``n_clients > 1`` every leaf has a leading clients axis and all
     clients get the same values.  Each leaf's key folds in a hash of its
@@ -85,15 +110,18 @@ def make(key, like, n_clients: int = 1):
         name = leaf_name(path)
         shape = leaf.shape[1:] if n_clients > 1 else leaf.shape
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7fffffff)
-        x = _leaf(name, shape, leaf.dtype, k, name.startswith("layers/"))
+        lead = (stacked[name] if stacked is not None
+                else int(name.startswith("layers/")))
+        x = _leaf(name, shape, leaf.dtype, k, lead)
         if n_clients > 1:
             x = jnp.broadcast_to(x[None], leaf.shape)
         out.append(x)
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def make_on_device(seed: int, like, shardings, n_clients: int = 1):
+def make_on_device(seed: int, like, shardings, n_clients: int = 1,
+                   stacked: dict | None = None):
     """``make`` as one jitted call, laid out as ``shardings``; the key is
     an argument, so one compiled program serves every seed."""
-    return jax.jit(lambda key: make(key, like, n_clients),
+    return jax.jit(lambda key: make(key, like, n_clients, stacked),
                    out_shardings=shardings)(seed_key(seed))

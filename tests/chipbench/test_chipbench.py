@@ -26,6 +26,7 @@ sys.path.insert(0, str(ROOT / "src"))
 
 from chipbench import cell as cellmod  # noqa: E402
 from chipbench import check, clock, ref_common, ref_dense, ref_hybrid  # noqa: E402
+from chipbench import ref_moe  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -115,6 +116,11 @@ def _tiny_bench(tmp_path, family="hybrid", limits=None, traffic=None):
     if family == "hybrid":
         model["ssm_state"] = 4
         arch = "hymba-1.5b"
+    if family == "moe":
+        # one leading dense layer, then two expert layers; every token goes
+        # to all 4 experts, so the program's capacity drops none
+        model.update(n_layers=3, moe=TINY_MOE)
+        arch = "mixtral-8x22b"
     with open(BENCH / "configs" / "hymba-1.5b.l16.json") as f:
         opt = json.load(f)["optimizer"]
     (bench / "configs" / "tiny.json").write_text(json.dumps(
@@ -136,6 +142,11 @@ def _tiny_bench(tmp_path, family="hybrid", limits=None, traffic=None):
     return bench
 
 
+TINY_MOE = {"n_experts": 4, "top_k": 4, "d_ff_expert": 32,
+            "n_shared_experts": 1, "first_k_dense": 1, "d_ff_dense": 64,
+            "aux_coef": 0.0}
+
+
 def test_cell_of_data_files_only_loads_by_name(tmp_path):
     bench = _tiny_bench(tmp_path)
     c = cellmod.load_cell("tiny.cell", bench, bench / "BENCHMARK.json")
@@ -150,6 +161,49 @@ def test_cell_of_data_files_only_loads_by_name(tmp_path):
     (bench / "configs" / "tiny.json").write_text(json.dumps(cfg))
     with pytest.raises(ValueError, match="architecture keys"):
         cellmod.load_cell("tiny.cell", bench, bench / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("workload", ["hymba16.silo1",
+                                      "danube4.silo1.s4096"])
+def test_existing_configs_give_the_registered_arch_with_their_keys(workload):
+    import dataclasses
+    from repro.configs.base import get_arch
+    c = cellmod.load_cell(workload)
+    t = c.traffic
+    want = get_arch(c.config["arch"]).replace(**c.config["model"])
+    want = want.replace(fl=dataclasses.replace(
+        want.fl, local_steps=t["local_steps"], schedule=t["schedule"],
+        role_policy=t["role_policy"]))
+    assert cellmod.arch_config(c) == want
+
+
+def test_architecture_keys_load_and_knobs_are_refused(tmp_path):
+    from repro.configs.base import get_arch
+    bench = _tiny_bench(tmp_path, "moe")
+    path = bench / "configs" / "tiny.json"
+    spec = bench / "BENCHMARK.json"
+    cfg = cellmod.arch_config(cellmod.load_cell("tiny.cell", bench, spec))
+    registered = get_arch("mixtral-8x22b").moe
+    assert cfg.moe.top_k == 4 != registered.top_k
+    assert cfg.moe.first_k_dense == 1 and cfg.moe.n_shared_experts == 1
+    # fields the configuration leaves out keep the registered values
+    assert cfg.moe.capacity_factor == registered.capacity_factor
+    base = json.loads(path.read_text())
+    base["model"]["ssm_conv"] = 4        # any architecture field
+    path.write_text(json.dumps(base))
+    assert cellmod.arch_config(
+        cellmod.load_cell("tiny.cell", bench, spec)).ssm_conv == 4
+    for knob in ({"remat": False}, {"moe": {"capacity_factor": 8.0}},
+                 {"moe": {"no_such_field": 1}}, {"no_such_field": 1}):
+        bad = json.loads(json.dumps(base))
+        for k, v in knob.items():
+            if isinstance(v, dict):
+                bad["model"][k].update(v)
+            else:
+                bad["model"][k] = v
+        path.write_text(json.dumps(bad))
+        with pytest.raises(ValueError, match="architecture keys"):
+            cellmod.load_cell("tiny.cell", bench, spec)
 
 
 # --------------------------------------------------------------------------
@@ -304,7 +358,7 @@ def test_worst_leaf_gap_is_against_reference_or_median_norm():
 # The reference against the program's own math, in float32
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["hybrid", "dense"])
+@pytest.mark.parametrize("family", ["hybrid", "dense", "moe"])
 def test_reference_gradients_match_program_math_in_float32(tmp_path, family):
     import jax
     import jax.numpy as jnp
@@ -317,7 +371,16 @@ def test_reference_gradients_match_program_math_in_float32(tmp_path, family):
         cfg, jax.random.PRNGKey(0)))
     p32 = jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32),
-        weights.make(weights.seed_key(7), like))
+        weights.make(weights.seed_key(7), like, 1,
+                     weights.stacking(model_api.param_decls(cfg))))
+    if family == "moe":
+        from repro.models import moe
+        tokens = 2 * 64       # one row at a time in the reference, both here
+        # no token dropped: each picks every expert once, and each expert's
+        # capacity holds every token
+        assert cfg.moe.top_k == cfg.moe.n_experts
+        assert moe.capacity(tokens, cfg.moe) >= tokens
+        assert {"dense_layers", "layers"} <= set(like)
     rng = np.random.default_rng(7)
     tok = rng.integers(0, cfg.vocab, (2, 64)).astype(np.int32)
     lab = rng.integers(0, cfg.vocab, (2, 64)).astype(np.int32)
@@ -335,6 +398,53 @@ def test_reference_gradients_match_program_math_in_float32(tmp_path, family):
         want = float(jnp.sqrt(jnp.sum(jnp.square(leaf))))
         assert np.sqrt(m_sq[name]) / 0.1 == pytest.approx(want, rel=1e-4), \
             name
+
+
+def test_family_hooks_take_the_place_of_the_defaults(tmp_path, monkeypatch):
+    """A family that states its own blocks, head and embedding (here the
+    defaults written out again) is followed as the defaults are."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    from chipbench import reference, weights
+    from repro.models import model_api
+    bench = _tiny_bench(tmp_path, "dense")
+    c = cellmod.load_cell("tiny.cell", bench, bench / "BENCHMARK.json")
+    cfg = cellmod.arch_config(c)
+    like = jax.eval_shape(lambda: model_api.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    p32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                 weights.make(weights.seed_key(3), like))
+    calls = []
+
+    def embed_in(top, tokens, model):
+        calls.append("embed_in")
+        return jnp.take(top["embed"]["in_table"], tokens, axis=0)
+
+    def head_loss(*args):
+        calls.append("head_loss")
+        return ref_common.head_loss(*args)
+
+    def blocks(params):
+        calls.append("blocks")
+        return ref_common.blocks(params)
+
+    fam = types.ModuleType("chipbench.ref_tinyhooks")
+    fam.layer, fam.flops_per_token = ref_dense.layer, ref_dense.flops_per_token
+    fam.embed_in, fam.head_loss, fam.blocks = embed_in, head_loss, blocks
+    monkeypatch.setitem(sys.modules, "chipbench.ref_tinyhooks", fam)
+    rng = np.random.default_rng(3)
+    batch = {"tokens": rng.integers(0, cfg.vocab, (2, 64)).astype(np.int32),
+             "labels": rng.integers(0, cfg.vocab, (2, 64)).astype(np.int32)}
+    got = {}
+    for name in ("dense", "tinyhooks"):
+        ref = reference.Reference(dict(c.config, family=name), p32, 1000)
+        got[name] = (float(ref.train_step(batch)), ref.sq_norms("m"))
+    assert set(calls) == {"embed_in", "head_loss", "blocks"}
+    assert got["tinyhooks"][0] == pytest.approx(got["dense"][0], rel=1e-6)
+    assert set(got["tinyhooks"][1]) == set(got["dense"][1])
+    for k, v in got["dense"][1].items():
+        assert got["tinyhooks"][1][k] == pytest.approx(v, rel=1e-5), k
 
 
 def test_weights_are_made_again_bit_for_bit():
@@ -360,6 +470,81 @@ def test_weights_are_made_again_bit_for_bit():
                               np.asarray(c["layers"]["wq"], np.float32))
     std = float(np.std(np.asarray(a["layers"]["wq"], np.float32)))
     assert 0.5 / np.sqrt(8) < std < 2 / np.sqrt(8)
+
+
+# sha256 over every leaf's name and bytes of the tiny hybrid and dense
+# models' weights from seed 2**33 + 7 (``_weights_digest``), as made before
+# the fan-in followed the program's declarations: they must not change
+PINNED_DIGESTS = {
+    "hybrid": "b0da0146a2ac71e3c2e58dcdcfa92c5e1edefd08b0b7d8187d73ac77c7303063",
+    "dense": "628c822332682a2f324e9ace04c30cffc5200887a80c87d7668a4ff84a41b6e4",
+}
+
+
+def _tiny_weights(tmp_path, family, declared=True):
+    import jax
+    from chipbench import weights
+    from repro.models import model_api
+    bench = _tiny_bench(tmp_path, family)
+    cfg = cellmod.arch_config(
+        cellmod.load_cell("tiny.cell", bench, bench / "BENCHMARK.json"))
+    like = jax.eval_shape(lambda: model_api.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    stacked = (weights.stacking(model_api.param_decls(cfg)) if declared
+               else None)
+    return weights.make(weights.seed_key(2 ** 33 + 7), like, 1, stacked)
+
+
+def _weights_digest(tree) -> str:
+    import hashlib
+    import jax
+    from chipbench import weights
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        h.update(weights.leaf_name(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("family", ["hybrid", "dense"])
+def test_weights_of_existing_families_are_pinned(tmp_path, family):
+    for declared in (True, False):
+        sub = tmp_path / str(declared)
+        assert _weights_digest(_tiny_weights(sub, family, declared)) == \
+            PINNED_DIGESTS[family]
+
+
+def test_spread_follows_the_contracted_input_width(tmp_path):
+    w = _tiny_weights(tmp_path, "moe")
+    moe, f = TINY_MOE, TINY_MOE["d_ff_expert"]
+
+    def std(a):
+        return float(np.std(np.asarray(a, np.float32)))
+
+    # (layers, experts, d, f) contracts d; (layers, experts, f, d) f
+    for leaf, fan_in in (("w_gate", 64), ("w_up", 64), ("w_down", f)):
+        s = std(w["layers"]["moe"][leaf])
+        assert 0.5 / np.sqrt(fan_in) < s < 2 / np.sqrt(fan_in), leaf
+    assert w["layers"]["moe"]["w_gate"].shape[1] == moe["n_experts"]
+    # the router (layers, d, experts) contracts d
+    s = std(w["layers"]["moe"]["router"])
+    assert 0.5 / 8 < s < 2 / 8
+    # a stack of layers under another name counts as one
+    for leaf, fan_in in (("w_gate", 64), ("w_down", moe["d_ff_dense"])):
+        s = std(w["dense_layers"]["mlp"][leaf])
+        assert 0.5 / np.sqrt(fan_in) < s < 2 / np.sqrt(fan_in), leaf
+    assert w["dense_layers"]["mlp"]["w_gate"].shape[0] == 1
+
+
+def test_moe_flops_against_hand_count():
+    m = dict(TINY, n_layers=3, moe={
+        "n_experts": 4, "top_k": 2, "d_ff_expert": 16,
+        "n_shared_experts": 1, "first_k_dense": 1, "d_ff_dense": 32})
+    # attention 384 + 80 per layer; dense SwiGLU 3*2*8*32 = 1536; router
+    # 2*8*4 = 64, two experts 2*3*2*8*16 = 1536, shared 768; logits 160
+    attn = 384 + 80
+    assert ref_moe.flops_per_token(m, 4) == \
+        (attn + 1536) + 2 * (attn + 64 + 1536 + 768) + 160
 
 
 # --------------------------------------------------------------------------
